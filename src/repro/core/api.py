@@ -513,12 +513,29 @@ class TuningSession:
     # -- wiring ---------------------------------------------------------------
     def _make_measurement(self, exp_seed: int) -> BaseMeasurement:
         m = self._factory(exp_seed)
+        kind = getattr(m, "device_kind", None)
+        if kind is not None and hasattr(self.store, "put_meta"):
+            # the device rides the store with the measurements, so whoever
+            # indexes them later (a fleet collector, say) names the device
+            # they ran on rather than its own
+            self.store.put_meta(self._device_meta_key(), kind)
         if self.store is not None:
             m = DiskCachedMeasurement(
                 m, self.store, prefix=f"{self.cache_key}/seed={exp_seed}"
             )
         m.set_telemetry(self.telemetry)
         return m
+
+    def _device_meta_key(self) -> str:
+        return f"__device__|{self.cache_key}"
+
+    def measured_device(self) -> str | None:
+        """``device_kind`` of the device this spec's measurements in the
+        store ran on, for backends that measure on one (``None`` otherwise,
+        or when nothing was measured through a store)."""
+        if not hasattr(self.store, "get_meta"):
+            return None
+        return self.store.get_meta(self._device_meta_key())
 
     def _get_dataset(self) -> SampleDataset | None:
         if self._dataset is None and self.spec.dataset_size:

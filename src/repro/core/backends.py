@@ -54,6 +54,9 @@ class Backend:
     shard workers).  ``pipeline`` marks whether ``make`` accepts a
     ``pipeline_workers=`` kwarg (the staged compile-prefetch pipeline); the
     session driver refuses to silently drop the knob on backends without it.
+    ``uses_device`` marks a backend whose measurements run on the
+    accelerator: on a TPU host only the ``device`` executor may run it in
+    parallel, since a chip belongs to one process.
     """
 
     name: str
@@ -62,6 +65,7 @@ class Backend:
     true_optimum: Callable[..., tuple[dict, float]] | None = None
     serializable: bool = True
     pipeline: bool = False
+    uses_device: bool = False
 
 
 BACKENDS: dict[str, Backend] = {}
@@ -254,7 +258,11 @@ register_backend(
 )
 register_backend(
     Backend(
-        name="pallas", make=_make_pallas, default_space=_pallas_space, pipeline=True
+        name="pallas",
+        make=_make_pallas,
+        default_space=_pallas_space,
+        pipeline=True,
+        uses_device=True,
     )
 )
 register_backend(Backend(name="timing", make=_make_timing, serializable=False))

@@ -33,7 +33,9 @@ session merges deterministically by unit key.  Built-ins:
   pulls units as it frees up.  An 8-chip host runs the matrix ~8x wider
   with no process spawn or re-import; merges are bit-identical to
   ``serial`` because workers rebuild sessions from the same serialized
-  spec and seeds derive from the spec alone.
+  spec and seeds derive from the spec alone.  It is the only parallel
+  executor for a compiling backend (``pallas``) on a TPU host: a chip
+  belongs to one process, so ``process`` and ``futures`` refuse there.
 
 Scheduling: ``ExecutionPlan.scheduler`` selects ``"steal"`` (default — one
 unit per submission, ``as_completed`` streaming, telemetry counters for
@@ -215,8 +217,12 @@ def recover_shard_stores(session) -> int:
     d = os.path.dirname(base) or "."
     if not os.path.isdir(d):
         return 0
+    # sqlite keeps "-wal" / "-shm" / "-journal" files beside a store that is
+    # open or was killed; they belong to their shard and are not stores
     leftovers = sorted(
-        os.path.join(d, f) for f in os.listdir(d) if pattern.fullmatch(f)
+        os.path.join(d, f)
+        for f in os.listdir(d)
+        if pattern.fullmatch(f) and not f.endswith(("-wal", "-shm", "-journal"))
     )
     merge_shard_stores(session, leftovers)
     # a killed run's workers also leave trace.shard<k>.jsonl files beside the
@@ -244,6 +250,24 @@ def _check_shippable(session) -> dict:
             "backend (e.g. 'costmodel') for parallel runs"
         )
     return session.spec.to_dict()  # raises early if not serializable
+
+
+def _refuse_on_chip(session, executor: str) -> None:
+    """A backend that measures on the accelerator, on a TPU host: worker
+    processes would each try to open the chips the parent may already hold.
+    The ``device`` executor is the path that drives every chip from this
+    one process."""
+    if not session._backend.uses_device:
+        return
+    import jax
+
+    if jax.default_backend() == "tpu":
+        raise RuntimeError(
+            f"executor {executor!r} cannot run backend "
+            f"{session.spec.backend!r} on a TPU host: a chip belongs to one "
+            "process. Use executor='device', which pins one worker thread "
+            "to each chip from this process"
+        )
 
 
 def _make_payloads(
@@ -494,11 +518,16 @@ def _build_worker_state(ctx: dict, ident: int) -> dict:
 
 
 def _close_worker_state(state: dict | None) -> None:
-    """Flush a worker's shard store tail and its trace (counters + fh)."""
+    """Flush and close a worker's shard store and its trace (counters + fh);
+    a device-executor thread's store must be closed before the parent
+    absorbs it."""
     if state is None:
         return
     try:
-        state["session"].save_store()
+        session = state["session"]
+        session.save_store()
+        if hasattr(session.store, "close"):
+            session.store.close()
     finally:
         if state["telemetry"] is not None:
             state["telemetry"].close()
@@ -586,6 +615,7 @@ def _run_process_static(plan: ExecutionPlan) -> list[UnitResult]:
     import multiprocessing
 
     spec_dict = _check_shippable(plan.session)
+    _refuse_on_chip(plan.session, "process")
     payloads = _make_payloads(plan, spec_dict)
     pool = concurrent.futures.ProcessPoolExecutor(
         max_workers=len(payloads),
@@ -609,6 +639,7 @@ def _run_process(plan: ExecutionPlan) -> list[UnitResult]:
     import multiprocessing
 
     spec_dict = _check_shippable(plan.session)
+    _refuse_on_chip(plan.session, "process")
     ctx = _steal_context(plan, spec_dict)
     n = max(1, min(plan.max_workers, len(plan.units)))
     pool = concurrent.futures.ProcessPoolExecutor(
@@ -650,6 +681,7 @@ def _run_futures(plan: ExecutionPlan) -> list[UnitResult]:
     process, or remote adapter — drains the queue in completion order; under
     ``static`` the legacy one-payload-per-worker grouping is submitted."""
     spec_dict = _check_shippable(plan.session)
+    _refuse_on_chip(plan.session, "futures")
     if plan.scheduler == "static":
         payloads = _make_payloads(plan, spec_dict)
     else:

@@ -13,7 +13,13 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from ..common import KernelGeometry, clamped_index, split_grid, use_interpret
+from ..common import (
+    KernelGeometry,
+    clamped_index,
+    compiler_params,
+    split_grid,
+    use_interpret,
+)
 
 
 def _add_kernel(a_ref, b_ref, o_ref, *, bm: int, tz: int):
@@ -46,5 +52,6 @@ def add_pallas(a: jnp.ndarray, b: jnp.ndarray, g: KernelGeometry) -> jnp.ndarray
         in_specs=[spec, spec],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct(a.shape, a.dtype),
+        compiler_params=compiler_params(),
         interpret=use_interpret(),
     )(a, b)

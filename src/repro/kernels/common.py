@@ -6,9 +6,9 @@ Kernel geometry mirrors the cost model (repro.costmodel.kernel_cost):
     bn = 128 * t_y        block cols
     t_z                   row coarsening (row-tiles per grid step)
     w_x, w_y              region splits (grid decomposition)
-    w_z                   pipeline depth — on real TPU the Pallas/Mosaic
-                          pipeliner owns buffer counts, so w_z only enters
-                          the cost model (documented in DESIGN.md 2.1)
+    w_z                   pipeline depth — the Pallas/Mosaic pipeliner
+                          double-buffers every block, so w_z only enters
+                          the cost model
 
 Region splits use *clamped block indices*: the grid is
 (w_x * steps_r, w_y * steps_c) where steps cover ceil-divided padded
@@ -16,8 +16,11 @@ regions; indices past the edge clamp to the last block, which makes the
 duplicated writes idempotent and keeps every (config x shape) combination
 legal — matching the cost model's padding-waste semantics.
 
-On CPU (this container) kernels run with ``interpret=True``; on a real TPU
-backend the same pallas_call lowers to Mosaic.
+On the CPU backend kernels run with ``interpret=True`` (tests, rehearsals);
+on a TPU the same pallas_call lowers to Mosaic.  Every pallas_call asks
+Mosaic for :data:`VMEM_LIMIT_BYTES` of scoped VMEM, and the validity screen
+(``repro.pallas_bench.validity``) admits a geometry only when the kernel's
+own footprint model (``KernelBenchSpec.vmem_bytes``) fits that same budget.
 """
 
 from __future__ import annotations
@@ -27,8 +30,15 @@ from math import ceil
 from typing import Callable
 
 import jax
+from jax.experimental.pallas import tpu as pltpu
 
 Config = dict
+
+#: scoped VMEM every kernel asks Mosaic for, and the validity screen's limit.
+#: Half of the v5e's 128 MiB physical VMEM: Mosaic's own default (16 MiB)
+#: refuses mid-sized blocks, while the physical figure leaves no room for the
+#: compiler's internal scratch and register spills.
+VMEM_LIMIT_BYTES = 64 * 1024 * 1024
 
 
 @dataclass(frozen=True)
@@ -48,11 +58,15 @@ class KernelGeometry:
 @dataclass(frozen=True)
 class KernelBenchSpec:
     """What a kernel package publishes to the real-measurement backend
-    (:mod:`repro.pallas_bench`): its per-block resource model (same fields as
-    ``costmodel.KernelWorkload``, so the validity pre-screen and the
-    analytical model agree on VMEM footprints) plus the two callables the
-    bench harness needs — deterministic input materialization and the jitted
+    (:mod:`repro.pallas_bench`): its VMEM model, which the validity screen
+    holds against :data:`VMEM_LIMIT_BYTES`, plus the two callables the bench
+    harness needs — deterministic input materialization and the jitted
     entry point.
+
+    The default VMEM model is :func:`tiled_vmem_bytes` (blocks of
+    ``(rows_step, bn)`` tiles, ``scratch_tiles`` in-kernel ``(bm, bn)``
+    temporaries).  A kernel whose blocks are shaped otherwise passes its own
+    ``vmem_bytes(geometry, y)``.
 
     ``make_inputs(x, y, seed)`` must be a pure function of its arguments so
     shard workers rebuild bit-identical problems from a JSON spec alone.
@@ -68,10 +82,25 @@ class KernelBenchSpec:
     make_inputs: Callable[[int, int, int], tuple] = field(repr=False, default=None)
     run: Callable[..., object] = field(repr=False, default=None)
     n_outputs: int = 1
-    halo: int = 0
     scratch_tiles: int = 0
     bpe: int = 4
     wz_in_program: bool = False
+    vmem_bytes: Callable[[KernelGeometry, int], int] | None = field(
+        repr=False, default=None
+    )
+
+
+def tiled_vmem_bytes(bench: KernelBenchSpec, g: KernelGeometry) -> int:
+    """VMEM Mosaic allocates for a kernel tiled in ``(rows_step, bn)``
+    blocks: two pipeline buffers per input and output block, plus the
+    kernel's ``(bm, bn)`` temporaries."""
+    blocks = 2 * (bench.n_inputs + bench.n_outputs) * g.rows_step * g.bn
+    return (blocks + bench.scratch_tiles * g.bm * g.bn) * bench.bpe
+
+
+def compiler_params() -> pltpu.CompilerParams:
+    """Mosaic options every kernel of this package compiles with."""
+    return pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
 
 
 def geometry_from_config(cfg: Config) -> KernelGeometry:
@@ -104,5 +133,17 @@ def clamped_index(region: int, local: int, steps: int, n_blocks: int) -> int:
 
 
 def use_interpret() -> bool:
-    """Pallas interpret mode on CPU; compiled Mosaic on TPU."""
-    return jax.default_backend() != "tpu"
+    """Pallas interpret mode on the CPU backend; compiled Mosaic on a TPU.
+
+    Any other backend raises: these kernels lower only through Mosaic, and
+    interpreting them there would time the interpreter, not the kernel.
+    """
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"no Pallas path for backend {backend!r}: the kernels compile for a "
+        "TPU and run interpreted on the CPU backend only"
+    )

@@ -21,7 +21,13 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from ..common import KernelGeometry, clamped_index, split_grid, use_interpret
+from ..common import (
+    KernelGeometry,
+    clamped_index,
+    compiler_params,
+    split_grid,
+    use_interpret,
+)
 from .ref import HARRIS_K
 
 
@@ -115,5 +121,6 @@ def harris_pallas(img: jnp.ndarray, g: KernelGeometry, k: float = HARRIS_K) -> j
         ],
         out_specs=pl.BlockSpec((rows, y), mid_idx),
         out_shape=jax.ShapeDtypeStruct(img.shape, img.dtype),
+        compiler_params=compiler_params(),
         interpret=use_interpret(),
     )(img, img, img)

@@ -27,6 +27,9 @@ def _conv3_valid(img: jnp.ndarray, kern: jnp.ndarray) -> jnp.ndarray:
         kern[None, None].astype(img.dtype),
         window_strides=(1, 1),
         padding="VALID",
+        # full f32 on every backend: a TPU's default convolution precision
+        # rounds f32 operands to bf16, which would fail the oracle itself
+        precision=lax.Precision.HIGHEST,
     )
     return out[0, 0]
 

@@ -4,7 +4,15 @@ Compute-bound, zero input bytes: each grid step derives its pixel
 coordinates from the block indices with broadcasted iota and runs the
 fixed-trip escape loop on the VPU.  Tunables shape the grid exactly like
 the add kernel (blocks (8*t_x*t_z, 128*t_y), region splits w_x/w_y with
-clamped idempotent indices).
+clamped idempotent indices), and the body walks the block's t_z row
+sub-tiles of (8*t_x, 128*t_y) pixels one at a time, so the escape loop's
+live vectors are one sub-tile wide whatever the block height.
+
+Mosaic constraints the body is written around: iota is integer-only (the
+pixel indices are cast to float afterwards), and the loop carries start
+from a vector computed from both pixel coordinates: a splat constant, or a
+vector that varies along one axis only, gets a replicated layout that the
+loop body's result cannot be relaid into.
 """
 
 from __future__ import annotations
@@ -13,12 +21,18 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from ..common import KernelGeometry, clamped_index, split_grid, use_interpret
+from ..common import (
+    KernelGeometry,
+    clamped_index,
+    compiler_params,
+    split_grid,
+    use_interpret,
+)
 from .ref import MAX_ITER, VIEW
 
 
 def _mandel_kernel(
-    o_ref, *, rows: int, bn: int, x: int, y: int,
+    o_ref, *, bm: int, tz: int, bn: int, x: int, y: int,
     steps_r: int, nblk_r: int, steps_c: int, nblk_c: int,
     max_iter: int, view,
 ):
@@ -28,27 +42,30 @@ def _mandel_kernel(
 
     xmin, xmax, ymin, ymax = view
     dtype = o_ref.dtype
-    row0 = (rb * rows).astype(dtype)
-    col0 = (cb * bn).astype(dtype)
-    rr = row0 + jax.lax.broadcasted_iota(dtype, (rows, bn), 0)
-    cc = col0 + jax.lax.broadcasted_iota(dtype, (rows, bn), 1)
-    cre = xmin + (cc + 0.5) * ((xmax - xmin) / y)
-    cim = ymin + (rr + 0.5) * ((ymax - ymin) / x)
+    cols = cb * bn + jax.lax.broadcasted_iota(jnp.int32, (bm, bn), 1)
+    cre = xmin + (cols.astype(dtype) + 0.5) * ((xmax - xmin) / y)
 
-    def body(_, state):
-        zr, zi, count = state
-        alive = zr * zr + zi * zi < 4.0
-        zr2 = zr * zr - zi * zi + cre
-        zi2 = 2.0 * zr * zi + cim
-        return (
-            jnp.where(alive, zr2, zr),
-            jnp.where(alive, zi2, zi),
-            count + alive.astype(dtype),
-        )
+    def sub_tile(t, _):
+        rows = (rb * tz + t) * bm + jax.lax.broadcasted_iota(jnp.int32, (bm, bn), 0)
+        cim = ymin + (rows.astype(dtype) + 0.5) * ((ymax - ymin) / x)
+        zero = (cre + cim) * 0.0
 
-    zeros = jnp.zeros((rows, bn), dtype)
-    _, _, count = jax.lax.fori_loop(0, max_iter, body, (zeros, zeros, zeros))
-    o_ref[...] = count
+        def body(_, state):
+            zr, zi, count = state
+            alive = zr * zr + zi * zi < 4.0
+            zr2 = zr * zr - zi * zi + cre
+            zi2 = 2.0 * zr * zi + cim
+            return (
+                jnp.where(alive, zr2, zr),
+                jnp.where(alive, zi2, zi),
+                count + alive.astype(dtype),
+            )
+
+        _, _, count = jax.lax.fori_loop(0, max_iter, body, (zero, zero, zero))
+        o_ref[pl.ds(t * bm, bm), :] = count
+        return ()
+
+    jax.lax.fori_loop(0, tz, sub_tile, ())
 
 
 def mandelbrot_pallas(
@@ -71,7 +88,7 @@ def mandelbrot_pallas(
 
     return pl.pallas_call(
         lambda o: _mandel_kernel(
-            o, rows=rows, bn=g.bn, x=x, y=y,
+            o, bm=g.bm, tz=g.tz, bn=g.bn, x=x, y=y,
             steps_r=steps_r, nblk_r=nblk_r, steps_c=steps_c, nblk_c=nblk_c,
             max_iter=max_iter, view=view,
         ),
@@ -79,5 +96,6 @@ def mandelbrot_pallas(
         in_specs=[],
         out_specs=pl.BlockSpec((rows, g.bn), idx),
         out_shape=jax.ShapeDtypeStruct((x, y), dtype),
+        compiler_params=compiler_params(),
         interpret=use_interpret(),
     )()

@@ -41,6 +41,7 @@ with no change here — only the provenance dict's ``interpret``/
 from __future__ import annotations
 
 import threading
+from collections import Counter
 from concurrent.futures import Future, ThreadPoolExecutor
 from contextlib import contextmanager
 from typing import Callable, Sequence
@@ -210,15 +211,21 @@ class PallasMeasurement(BaseMeasurement):
         self._pcache_hit()
         return fn
 
-    def _compile_aot(self, inputs: tuple, run_cfg: Config):
-        """AOT-compile the program (``jit(...).lower().compile()``) so its
-        executable can be published to the persistent cache.  Returns
-        ``(warmed callable, serialized blob | None)``, or ``(None, None)``
-        when AOT lowering fails — the jit-closure fallback then owns the
-        compile (and the penalty, if the config is genuinely invalid)."""
-        import jax
+    def _compile_warm(self, inputs: tuple, run_cfg: Config):
+        """Compile and warm cfg's program; raises what the compiler or the
+        first runs raise.  Returns ``(warmed callable, serialized blob |
+        None)``: with a persistent cache attached the program is compiled
+        ahead of time (``jit(...).lower().compile()``) so its executable can
+        be published; without one, the kernel's own jitted entry point
+        compiles on its first call."""
+        if self.pcache is None:
+            def fn():
+                return self.workload.run(inputs, run_cfg)
 
-        try:
+            blob = None
+        else:
+            import jax
+
             compiled = (
                 jax.jit(lambda *arrays: self.workload.run(arrays, run_cfg))
                 .lower(*inputs)
@@ -228,12 +235,10 @@ class PallasMeasurement(BaseMeasurement):
             def fn():
                 return compiled(*inputs)
 
-            fence(fn())                   # first run (compile() is lazy-free)
-            for _ in range(max(0, self.warmup - 1)):
-                fence(fn())
-        except Exception:  # noqa: BLE001 — fall back to the closure path
-            return None, None
-        return fn, serialize_compiled(compiled)
+            blob = serialize_compiled(compiled)
+        for _ in range(max(1, self.warmup)):
+            fence(fn())
+        return fn, blob
 
     def _compile_now(self, cfg: Config, gkey: tuple) -> Callable | InvalidMeasurement:
         """Trace + lower + warm cfg's geometry, populating the cache.  Called
@@ -286,30 +291,22 @@ class PallasMeasurement(BaseMeasurement):
                 self.run_compiles += 1
             if self.telemetry.enabled:
                 self.telemetry.inc("compiles")
-            fn = None
-            artifact = None
-            if pc is not None:
-                fn, artifact = self._compile_aot(inputs, run_cfg)
-            if fn is None:
-                def fn():
-                    return self.workload.run(inputs, run_cfg)
-
-                try:
-                    fence(fn())                   # trace + lower + first run
-                    for _ in range(max(0, self.warmup - 1)):
-                        fence(fn())
-                except Exception as e:  # noqa: BLE001 — any compile failure is a penalty
-                    bad = InvalidMeasurement(
-                        reason=f"{type(e).__name__}: {e}", stage="compile"
+            try:
+                fn, artifact = self._compile_warm(inputs, run_cfg)
+            except Exception as e:  # noqa: BLE001 — any compile failure is a penalty
+                # counted per stage in provenance(): a screened-in config
+                # the compiler refuses is a screen defect to report
+                bad = InvalidMeasurement(
+                    reason=f"{type(e).__name__}: {e}", stage="compile"
+                )
+                with self._cache_lock:
+                    self._compiled[gkey] = bad
+                if claimed:
+                    pc.put(
+                        pckey, status="invalid",
+                        reason=bad.reason, stage="compile",
                     )
-                    with self._cache_lock:
-                        self._compiled[gkey] = bad
-                    if claimed:
-                        pc.put(
-                            pckey, status="invalid",
-                            reason=bad.reason, stage="compile",
-                        )
-                    return bad
+                return bad
             with self._cache_lock:
                 self._compiled[gkey] = fn
             if claimed:
@@ -435,7 +432,15 @@ class PallasMeasurement(BaseMeasurement):
         """Compile phase: submit every geometry this batch will compile to
         the pool, in batch order.  Only configs that pass the pre-screen are
         prefetched (the inline path never compiles a screened-out config),
-        so ``n_compiles`` is identical with the pipeline on or off."""
+        so ``n_compiles`` is identical with the pipeline on or off.
+
+        Pool threads do not inherit the caller's thread-local
+        ``jax.default_device`` (the device executor pins each worker thread
+        to its chip that way), so each task re-enters the caller's pin:
+        inputs and executables land on the caller's device, not device 0."""
+        import jax
+
+        device = jax.config.jax_default_device
         if self._pool is None:
             self._pool = ThreadPoolExecutor(
                 max_workers=self.pipeline_workers,
@@ -451,14 +456,16 @@ class PallasMeasurement(BaseMeasurement):
                 if gkey in self._compiled or gkey in self._inflight:
                     continue
                 self._inflight[gkey] = self._pool.submit(
-                    self._prefetch_task, dict(cfg), gkey
+                    self._prefetch_task, dict(cfg), gkey, device
                 )
                 depth = len(self._inflight)
             if self.telemetry.enabled:
                 self.telemetry.gauge("prefetch_inflight", depth)
 
-    def _prefetch_task(self, cfg: Config, gkey: tuple):
-        with self._staged("compile", key=str(gkey)):
+    def _prefetch_task(self, cfg: Config, gkey: tuple, device):
+        import jax
+
+        with jax.default_device(device), self._staged("compile", key=str(gkey)):
             return self._compile_now(cfg, gkey)
 
     def measure_batch(self, configs: Sequence[Config]) -> np.ndarray:
@@ -508,17 +515,39 @@ class PallasMeasurement(BaseMeasurement):
     def stage_times(self) -> dict[str, float]:
         return self.clock.times()
 
+    @property
+    def device_kind(self) -> str:
+        """The kind of device the kernels run on: the thread's
+        ``jax.default_device`` (the device executor's pin), else the default
+        backend's first device."""
+        import jax
+
+        dev = jax.config.jax_default_device
+        if dev is None or isinstance(dev, str):
+            dev = jax.devices(dev)[0]
+        return dev.device_kind
+
     def provenance(self) -> dict:
         """Backend provenance for the versioned RunRecord: how timings were
         taken and on what — the fields that distinguish an interpret-mode CPU
         run from a real-TPU run of the same spec.  Counters are per-run
         (since the last ``reset()``): a later matrix cell reports its own
         compiles/penalties, not lifetime totals; ``n_compiles_total`` keeps
-        the lifetime figure (== compilation-cache fills)."""
+        the lifetime figure (== compilation-cache fills).  ``penalties``
+        counts penalized configs by stage (``validity`` / ``compile`` /
+        ``run``) and ``failures`` lists the compile- and run-stage ones
+        with their reasons."""
         import jax
 
-        dev = jax.devices()[0]
         stage_s = {k: round(v, 6) for k, v in self.clock.times().items()}
+        penalties = Counter(self.invalid[k].stage for k in self._run_invalid)
+        # configs that passed the screen and still failed: what a tuning
+        # run on the chip must report rather than absorb as inf
+        failures = {
+            k: self.invalid[k].to_meta()
+            for k in sorted(self._run_invalid)
+            if self.invalid[k].stage != "validity"
+        }
         return {
             "backend": "pallas",
             "kernel": self.workload.name,
@@ -527,7 +556,7 @@ class PallasMeasurement(BaseMeasurement):
             "input_seed": self.workload.input_seed,
             "interpret": bool(self.workload.interpret()),
             "platform": jax.default_backend(),
-            "device_kind": dev.device_kind,
+            "device_kind": self.device_kind,
             "repeats": self.repeats,
             "warmup": self.warmup,
             "timer": "perf_counter",
@@ -538,6 +567,8 @@ class PallasMeasurement(BaseMeasurement):
             "n_compiles_total": self.n_compiles,
             "n_pcache_hits": self.run_pcache_hits,
             "n_invalid": len(self._run_invalid),
+            "penalties": dict(penalties),
+            "failures": failures,
         }
 
     def reset(self) -> None:

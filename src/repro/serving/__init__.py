@@ -34,7 +34,7 @@ from .winners import (
     nearest_winner,
     record_session_winner,
     record_winner,
-    spec_geometry,
+    session_geometry,
 )
 
 __all__ = [
@@ -53,5 +53,5 @@ __all__ = [
     "open_serve_store",
     "record_session_winner",
     "record_winner",
-    "spec_geometry",
+    "session_geometry",
 ]

@@ -121,15 +121,21 @@ def parse_winner_key(key: str) -> tuple[str, int, int, str] | None:
 # ----------------------------------------------------------------- geometry
 
 
-def spec_geometry(spec) -> tuple[int, int, str] | None:
-    """The ``(x, y, device)`` a spec's winner is indexed under.
+def session_geometry(session) -> tuple[int, int, str] | None:
+    """The ``(x, y, device)`` a session's winner is indexed under.
 
     The costmodel backend measures a fixed per-kernel workload geometry
     (``repro.costmodel.WORKLOADS``) on a named chip model; the pallas
-    backend measures the geometry in its backend kwargs on the live device.
-    Backends with no geometry notion (``timing`` / ``callable`` wrappers)
-    return ``None`` — their runs don't index winners.
+    backend measures the geometry in its backend kwargs, and its winners
+    are indexed under the ``device_kind`` the measurements ran on
+    (``"TPU v5 lite"``, or ``"cpu"`` for interpret mode), as the measuring
+    session wrote it into the store — so a chip winner and an
+    interpret-mode winner never share a key, and a collector on another
+    host files them correctly.  ``None`` for a pallas store that names no
+    device, and for backends with no geometry notion (``timing`` /
+    ``callable`` wrappers): their runs don't index winners.
     """
+    spec = session.spec
     if spec.backend == "costmodel":
         from ..costmodel import WORKLOADS
 
@@ -140,9 +146,12 @@ def spec_geometry(spec) -> tuple[int, int, str] | None:
     if spec.backend == "pallas":
         from ..pallas_bench import DEFAULT_X, DEFAULT_Y
 
+        device = session.measured_device()
+        if device is None:
+            return None
         x = int(spec.backend_kwargs.get("x") or DEFAULT_X)
         y = int(spec.backend_kwargs.get("y") or DEFAULT_Y)
-        return x, y, str(spec.backend_kwargs.get("device") or "pallas")
+        return x, y, device
     return None
 
 
@@ -234,7 +243,7 @@ def record_session_winner(session) -> WinnerRecord | None:
     store = getattr(session, "store", None)
     if store is None:
         return None
-    geom = spec_geometry(session.spec)
+    geom = session_geometry(session)
     if geom is None:
         return None
     best = best_store_entry(store, session.cache_key)

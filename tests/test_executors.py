@@ -265,6 +265,25 @@ def test_parallel_run_rejects_in_process_overrides():
             session.run_matrix(executor=executor, max_workers=2)
 
 
+@pytest.mark.parametrize("scheduler", ["steal", "static"])
+def test_child_executors_refuse_device_backend_on_tpu(monkeypatch, scheduler):
+    """Worker processes cannot share a chip: on a TPU host the pallas
+    backend (``uses_device``) runs under the device executor, and the others
+    say so."""
+    import jax
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    spec = TuningSpec(
+        kernel="add", backend="pallas", backend_kwargs={"x": 16, "y": 256},
+        algorithms=("rs",), design=ExperimentDesign(sample_sizes=(2,), n_experiments=(2,)),
+    )
+    for executor in ("process", "futures"):
+        with pytest.raises(RuntimeError, match="executor='device'"):
+            TuningSession(spec).run_matrix(
+                executor=executor, max_workers=2, scheduler=scheduler
+            )
+
+
 # ------------------------------------------------------------ kill-and-resume
 
 

@@ -5,7 +5,15 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import add, add_ref, harris, harris_ref, mandelbrot, mandelbrot_ref
+from repro.kernels import (
+    add,
+    add_ref,
+    harris,
+    harris_ref,
+    mandelbrot,
+    mandelbrot_ref,
+    reference_mismatch,
+)
 
 CONFIGS = [
     {},                                                   # defaults
@@ -26,10 +34,7 @@ def test_add_matches_ref(shape, cfg, dtype):
     b = jnp.asarray(rng.normal(size=shape), dtype)
     out = add(a, b, cfg)
     assert out.dtype == dtype
-    np.testing.assert_allclose(
-        np.asarray(out, np.float32), np.asarray(add_ref(a, b), np.float32),
-        rtol=1e-6, atol=1e-6,
-    )
+    assert reference_mismatch("add", out, add_ref(a, b)) is None
 
 
 @pytest.mark.parametrize("shape", SHAPES)
@@ -37,10 +42,7 @@ def test_add_matches_ref(shape, cfg, dtype):
 def test_harris_matches_ref(shape, cfg):
     rng = np.random.default_rng(1)
     img = jnp.asarray(rng.normal(size=shape), jnp.float32)
-    out = np.asarray(harris(img, cfg))
-    ref = np.asarray(harris_ref(img))
-    denom = np.abs(ref).max()
-    assert np.abs(out - ref).max() / denom < 1e-5
+    assert reference_mismatch("harris", harris(img, cfg), harris_ref(img)) is None
 
 
 @pytest.mark.parametrize("shape", [(64, 128), (96, 256), (50, 130)])
@@ -51,11 +53,8 @@ def test_mandelbrot_matches_ref(shape, cfg):
     iterations -> 'discrete boundary' tolerance: >=99.5% exact, violations
     within +-4."""
     x, y = shape
-    out = np.asarray(mandelbrot(x, y, cfg))
-    ref = np.asarray(mandelbrot_ref(x, y))
-    exact = (out == ref).mean()
-    assert exact >= 0.995, exact
-    assert np.abs(out - ref).max() <= 4
+    mismatch = reference_mismatch("mandelbrot", mandelbrot(x, y, cfg), mandelbrot_ref(x, y))
+    assert mismatch is None, mismatch
 
 
 def test_mandelbrot_interior_is_max_iter():
@@ -70,3 +69,13 @@ def test_add_odd_shapes_pad_correctly():
     b = jnp.asarray(rng.normal(size=(56, 200)), jnp.float32)
     out = add(a, b, dict(t_x=3, t_y=1, t_z=2, w_x=2, w_y=3))
     np.testing.assert_allclose(out, a + b, rtol=1e-6)
+
+
+def test_reference_mismatch_names_what_failed():
+    ref = jnp.zeros((8, 128), jnp.float32)
+    assert reference_mismatch("add", ref + 1e-3, ref).startswith("exceeds")
+    assert reference_mismatch("add", ref.at[0, 0].set(jnp.nan), ref) == "non-finite output"
+    assert reference_mismatch("harris", ref[:4], ref).startswith("shape")
+    one_off = ref.at[:1].set(5.0)            # 1/8 of pixels off by 5
+    assert "exact" in reference_mismatch("mandelbrot", one_off, ref)
+    assert reference_mismatch("mandelbrot", ref.at[0, 0].set(3.0), ref) is None

@@ -116,7 +116,7 @@ def test_validate_rules():
     cfg = dict(t_x=16, t_y=16, t_z=2, w_x=1, w_y=1, w_z=8)
     r = validate_config(big, cfg, vmem_limit=1 << 20)
     assert r is not None and r.startswith("vmem:")
-    assert vmem_footprint(big.bench, geometry_from_config(cfg)) > (1 << 20)
+    assert vmem_footprint(big.bench, geometry_from_config(cfg), big.y) > (1 << 20)
     # grid bound
     r = validate_config(w, GOOD, max_grid=1)
     assert r is not None and r.startswith("grid:")
@@ -144,6 +144,8 @@ def test_measure_valid_and_invalid():
     assert m.reason_for(GOOD) is None
     # invalid configs never reach the compiler
     assert m.n_compiles == 1
+    prov = m.provenance()
+    assert prov["penalties"] == {"validity": 1} and prov["failures"] == {}
 
 
 def test_compile_cache_shared_across_wz():
@@ -177,6 +179,11 @@ def test_run_failure_maps_to_penalty():
     assert m.reason_for(GOOD).startswith("compile:")
     # the failed geometry is cached: no retry on the next proposal
     assert math.isinf(m.measure({**GOOD, "w_z": 2})) and m.n_compiles == 1
+    # a screened-in config that failed to compile is counted and named
+    prov = m.provenance()
+    assert prov["penalties"] == {"compile": 2}
+    assert set(prov["failures"]) == {config_key(GOOD), config_key({**GOOD, "w_z": 2})}
+    assert all("mosaic says no" in r for r in prov["failures"].values())
 
 
 def test_measure_final_reuses_compiled_program():

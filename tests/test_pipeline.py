@@ -26,6 +26,7 @@ from repro.core import (
     build_units,
 )
 from repro.core.api import STEAL_OVERSPLIT
+from repro.core.stores import make_store
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -323,18 +324,21 @@ def test_device_executor_failure_then_resume(tmp_path, monkeypatch):
 # ------------------------------------------------------------ device executor
 
 
-def store_values_bytes(path: str) -> bytes:
-    return json.dumps(
-        sorted(MeasurementStore(path).items()), sort_keys=True
-    ).encode()
+def store_values_bytes(path: str, kind: str = "json") -> bytes:
+    store = make_store(kind, path)
+    items = sorted(store.items())
+    if hasattr(store, "close"):
+        store.close()
+    return json.dumps(items, sort_keys=True).encode()
 
 
-def test_device_executor_bit_identical_to_serial(tmp_path):
-    serial_path = str(tmp_path / "serial.json")
-    device_path = str(tmp_path / "device.json")
-    base = TuningSession(SPEC.replace(store="json", store_path=serial_path))
+@pytest.mark.parametrize("kind", ["json", "sqlite"])
+def test_device_executor_bit_identical_to_serial(tmp_path, kind):
+    serial_path = str(tmp_path / f"serial.{kind}")
+    device_path = str(tmp_path / f"device.{kind}")
+    base = TuningSession(SPEC.replace(store=kind, store_path=serial_path))
     serial = base.run_matrix()
-    dev_session = TuningSession(SPEC.replace(store="json", store_path=device_path))
+    dev_session = TuningSession(SPEC.replace(store=kind, store_path=device_path))
     with pytest.warns(UserWarning):       # single-device host: capped
         device = dev_session.run_matrix(executor="device", max_workers=2)
     for key in serial.cells:
@@ -342,7 +346,12 @@ def test_device_executor_bit_identical_to_serial(tmp_path):
             serial.cells[key].final_values, device.cells[key].final_values
         )
     assert base.last_record.result["cells"] == dev_session.last_record.result["cells"]
-    assert store_values_bytes(serial_path) == store_values_bytes(device_path)
+    for session in (base, dev_session):
+        if hasattr(session.store, "close"):
+            session.store.close()
+    assert store_values_bytes(serial_path, kind) == store_values_bytes(device_path, kind)
+    # device threads close their shard stores before the parent absorbs them
+    # (an open sqlite shard leaves "-wal"/"-shm" files beside it)
     assert not [f for f in os.listdir(tmp_path) if ".shard" in f]
 
 
@@ -391,3 +400,61 @@ def test_device_executor_on_four_fake_devices(tmp_path):
     )
     assert out.returncode == 0, out.stderr
     assert "DEVICE_OK" in out.stdout
+
+
+PREFETCH_PIN_SCRIPT = """
+import json, threading
+import jax
+assert len(jax.devices()) == 4, jax.devices()
+from repro.core import ExperimentDesign, TuningSession, TuningSpec
+from repro.pallas_bench.workloads import PallasWorkload
+
+held = {"inputs": set(), "outputs": set()}
+lock = threading.Lock()
+materialize, run = PallasWorkload.materialize, PallasWorkload.run
+
+def recording_materialize(self):
+    arrays = materialize(self)
+    with lock:
+        held["inputs"].update(d.id for a in arrays for d in a.devices())
+    return arrays
+
+def recording_run(self, inputs, cfg):
+    out = run(self, inputs, cfg)
+    with lock:
+        held["outputs"].update(d.id for d in out.devices())
+    return out
+
+PallasWorkload.materialize = recording_materialize
+PallasWorkload.run = recording_run
+spec = TuningSpec(
+    kernel="add", backend="pallas",
+    backend_kwargs={"x": 16, "y": 256, "repeats": 1},
+    algorithms=("rs",),
+    design=ExperimentDesign(sample_sizes=(4,), n_experiments=(4,),
+                            final_repeats=1),
+    seed=3,
+)
+TuningSession(spec).run_matrix(
+    executor="device", max_workers=4, pipeline_workers=2, scheduler="static"
+)
+print("HELD", json.dumps({k: sorted(v) for k, v in held.items()}))
+"""
+
+
+def test_device_executor_prefetch_compiles_on_worker_device():
+    """With the compile prefetcher on, each device worker's inputs and
+    programs land on its own device: the prefetch pool's threads re-enter
+    the worker's ``jax.default_device`` pin instead of defaulting to
+    device 0."""
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    env["PYTHONPATH"] = os.path.join(ROOT, "src")
+    out = subprocess.run(
+        [sys.executable, "-c", PREFETCH_PIN_SCRIPT],
+        capture_output=True, text=True, env=env, timeout=600,
+    )
+    assert out.returncode == 0, out.stderr
+    line = next(ln for ln in out.stdout.splitlines() if ln.startswith("HELD "))
+    held = json.loads(line[len("HELD "):])
+    assert held == {"inputs": [0, 1, 2, 3], "outputs": [0, 1, 2, 3]}

@@ -209,6 +209,58 @@ def test_session_records_winner_transactionally(tmp_path):
     assert got.fresh > 0
 
 
+def test_pallas_winner_is_indexed_under_measured_device_kind(tmp_path):
+    """A real-kernel winner names the device it was measured on, so an
+    interpret-mode CPU winner and a chip winner never share a key."""
+    import jax
+
+    spec = TuningSpec(
+        kernel="add", backend="pallas",
+        backend_kwargs={"x": 16, "y": 256, "repeats": 1},
+        budget=2, final_repeats=1, seed=0,
+        store="json", store_path=str(tmp_path / "c.json"),
+    )
+    TuningSession(spec).run()
+    store = MeasurementStore(spec.store_path)
+    kind = jax.devices()[0].device_kind
+    assert [k for k, _ in store.winner_items()] == [f"add|x=16|y=256|{kind}"]
+    assert lookup_winner(store, "add", 16, 256, kind) is not None
+    assert best_config(store, "add", 16, 256, "pallas").status == "miss"
+
+
+def test_collector_indexes_pallas_winner_under_the_workers_device(
+    tmp_path, monkeypatch
+):
+    """A collector whose own device differs from the workers' (a CPU
+    coordinator over TPU workers) files the winner under the device the
+    measurements ran on, which the worker's shard store carries."""
+    import jax
+
+    from repro.core.executors import absorb_store
+    from repro.serving import record_session_winner
+
+    spec = TuningSpec(
+        kernel="add", backend="pallas",
+        backend_kwargs={"x": 16, "y": 256, "repeats": 1},
+        budget=2, final_repeats=1, seed=0,
+        store="json", store_path=str(tmp_path / "shard.json"),
+    )
+    TuningSession(spec).run()
+    worker_kind = jax.devices()[0].device_kind
+    parent = TuningSession(spec.replace(store_path=str(tmp_path / "parent.json")))
+    absorb_store(parent.store, "json", spec.store_path)
+
+    class OtherDevice:
+        platform, device_kind = "tpu", "collector-only device"
+
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: [OtherDevice()])
+    rec = record_session_winner(parent)
+    assert rec is not None and rec.device == worker_kind
+    assert [k for k, _ in parent.store.winner_items()] == [
+        f"add|x=16|y=256|{worker_kind}"
+    ]
+
+
 # ------------------------------------------------------------------- serving
 
 
