@@ -350,38 +350,6 @@ def table_scheduler_tail(slow_ms: float = 30.0, workers: int = 2) -> None:
     )
 
 
-def table_telemetry_overhead(budget: int = 400) -> None:
-    """Tracing cost on the hot path: the same tuning run with the default
-    no-op telemetry vs a real JSONL tracer.  The tuned result must be
-    identical (telemetry is observability only); the per-sample delta in µs
-    is the tracked overhead number."""
-    import shutil
-    import tempfile
-
-    from repro.telemetry import TRACE_FILE, Telemetry
-
-    spec = TuningSpec(kernel="harris", searcher="ga", budget=budget, seed=0)
-    t0 = time.perf_counter()
-    off = TuningSession(spec).run()
-    t_off = time.perf_counter() - t0
-
-    tmp = tempfile.mkdtemp(prefix="tel_overhead_")
-    try:
-        tel = Telemetry(os.path.join(tmp, TRACE_FILE))
-        t0 = time.perf_counter()
-        on = TuningSession(spec, telemetry=tel).run()
-        t_on = time.perf_counter() - t0
-        tel.close()
-    finally:
-        shutil.rmtree(tmp, ignore_errors=True)
-    same = int(on.best_value == off.best_value)
-    print(f"telemetry_overhead/off,{t_off/budget*1e6:.2f},budget={budget}")
-    print(
-        f"telemetry_overhead/on,{t_on/budget*1e6:.2f},"
-        f"delta_us={(t_on-t_off)/budget*1e6:.2f} identical={same}"
-    )
-
-
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--budget", type=int, default=500)
@@ -414,7 +382,6 @@ def main() -> None:
     table_pallas_backend()
     table_pipeline_overlap()
     table_scheduler_tail()
-    table_telemetry_overhead()
     print("# paper-claims validation")
     checks = validate(results_dir)
     for name, v in checks.items():

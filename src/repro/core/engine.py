@@ -46,9 +46,10 @@ def drive(
     histories are identical.  ``batch_size`` optionally caps how many configs
     are asked per iteration (e.g. to bound a remote executor's batch).
     ``telemetry`` (a :mod:`repro.telemetry` sink; default no-op) wraps each
-    ask/tell iteration in a ``round`` span and counts the non-finite
-    penalties told to the searcher — observability only, the loop's results
-    are identical with or without it.
+    ask/tell iteration in a ``round`` span, the searcher's own ``ask`` and
+    ``tell`` in spans of those names, and counts the non-finite penalties
+    told to the searcher — observability only, the loop's results are
+    identical with or without it.
     """
     if dispatch not in DISPATCH_MODES:
         raise ValueError(f"dispatch must be one of {DISPATCH_MODES}")
@@ -56,7 +57,8 @@ def drive(
     searcher.start(budget)
     rnd = 0
     while True:
-        configs = searcher.ask(batch_size)
+        with tel.span("ask", round=rnd):
+            configs = searcher.ask(batch_size)
         if not configs:
             break
         with tel.span("round", round=rnd, algo=searcher.name, asked=len(configs)):
@@ -66,7 +68,8 @@ def drive(
                 values = np.array(
                     [measurement.measure(c) for c in configs], dtype=np.float64
                 )
-            searcher.tell(configs, values)
+            with tel.span("tell", round=rnd):
+                searcher.tell(configs, values)
         if tel.enabled:
             bad = int(len(values) - np.count_nonzero(np.isfinite(values)))
             if bad:

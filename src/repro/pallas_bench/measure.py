@@ -252,7 +252,8 @@ class PallasMeasurement(BaseMeasurement):
         started later — skips this geometry entirely."""
         with self._cache_lock:
             if self._inputs is None:
-                self._inputs = self.workload.materialize()
+                with self._staged("inputs", sub=True):
+                    self._inputs = self.workload.materialize()
             inputs = self._inputs
         run_cfg = self._run_config(cfg)
         pc = self.pcache
@@ -320,22 +321,31 @@ class PallasMeasurement(BaseMeasurement):
 
     # -- pipeline stages -------------------------------------------------------
     @contextmanager
-    def _staged(self, name: str, **attrs):
+    def _staged(self, name: str, *, sub: bool = False, **attrs):
         """Charge the stage clock AND (when telemetry is on) emit a ``stage``
         trace event with the same duration — one timing source for both, so
         the trace's per-stage totals reconcile exactly with ``stage_times``.
-        Thread-safe like the clock: prefetch pool threads use it too."""
-        t0 = monotonic()
-        try:
-            yield
-        finally:
-            dur = monotonic() - t0
-            self.clock.add(name, dur)
-            if self.telemetry.enabled:
-                self.telemetry.stage(
-                    name, dur,
-                    **{k: v for k, v in attrs.items() if v is not None},
-                )
+        Thread-safe like the clock: prefetch pool threads use it too.  A
+        ``sub`` stage (``inputs``, inside ``compile``) is telemetry only:
+        the stage clock keeps the pipeline's four stages.  Inside the
+        region the telemetry sink receives JAX's compile phases and
+        annotates the profiler's trace (``Telemetry.stage_scope``).  The
+        event is stamped at the region's start on the writer's clock plus
+        the duration, so every event emitted inside the region lies inside
+        the stage's interval."""
+        with self.telemetry.stage_scope(name) as start:
+            t0 = monotonic()
+            try:
+                yield
+            finally:
+                dur = monotonic() - t0
+                if not sub:
+                    self.clock.add(name, dur)
+                if self.telemetry.enabled:
+                    self.telemetry.stage(
+                        name, dur, end=start + dur,
+                        **{k: v for k, v in attrs.items() if v is not None},
+                    )
 
     def _stage_screen(self, config: Config) -> InvalidMeasurement | None:
         """Validity pre-screen; ``None`` means the config may compile."""

@@ -19,7 +19,8 @@ Event types:
 
 ``begin`` / ``end``  span boundaries; ``span`` names the level of the fixed
                      hierarchy matrix > cell > unit > round > experiment >
-                     stage.  ``end`` carries ``dur`` (seconds) and ``ok:
+                     stage, or the searcher's ``ask`` / ``tell`` of each
+                     iteration.  ``end`` carries ``dur`` (seconds) and ``ok:
                      false`` when the span died on an exception.  ``cell``
                      spans are not emitted live (a cell's units may run on
                      several workers); consumers derive them by grouping
@@ -28,6 +29,11 @@ Event types:
 ``stage``            a completed pipeline-stage interval (screen / compile /
                      time / record) with ``dur`` and an optional config
                      ``key`` — the high-frequency complete-span form.
+                     Sub-stages nest inside them: ``inputs`` (a job's
+                     input arrays, in its first compile) and JAX's compile
+                     phases ``compile.trace`` / ``compile.lower`` /
+                     ``compile.backend``, whose ``within`` names the stage
+                     they ran in (:mod:`.jaxbridge`).
 ``plan``             emitted once by the parent when the unit plan is fixed:
                      ``units`` (keys), ``units_total``,
                      ``experiments_total``, and on resume

@@ -52,6 +52,9 @@ class NullTelemetry:
     def stage(self, name, dur, **attrs) -> None:
         pass
 
+    def stage_scope(self, name):
+        return _NULL_SPAN
+
     def inc(self, name, n=1) -> None:
         pass
 

@@ -17,6 +17,11 @@ Telemetry is a pure observability knob: nothing here feeds cache keys,
 journal namespaces, or measurement values (staticcheck rule OBS001 pins
 that), and a run with a writer attached produces bit-identical measurement
 stores to one without.
+
+Regions also reach JAX (:mod:`.jaxbridge`): every span and every pipeline
+stage enters a profiler annotation ``repro.<name>@<src>``, and a stage
+receives JAX's own compile phases as ``compile.trace`` / ``compile.lower`` /
+``compile.backend`` stage events.
 """
 
 from __future__ import annotations
@@ -27,6 +32,7 @@ import threading
 from contextlib import contextmanager
 
 from ..core.clock import monotonic
+from . import jaxbridge
 from .events import SHARD_RE, TRACE_FILE, shard_file
 
 
@@ -55,8 +61,14 @@ class Telemetry:
     def dir(self) -> str:
         return os.path.dirname(self.path) or "."
 
-    def _emit(self, ev: str, fields: dict) -> None:
-        record = {"t": round(float(self._clock()), 6), "src": self.src, "ev": ev}
+    def now(self) -> float:
+        """This writer's clock: what it stamps ``t`` with."""
+        return self._clock()
+
+    def _emit(self, ev: str, fields: dict, t: float | None = None) -> None:
+        if t is None:
+            t = self._clock()
+        record = {"t": round(float(t), 6), "src": self.src, "ev": ev}
         record.update(fields)
         with self._lock:
             # seq is assigned under the lock so it is a true total order for
@@ -78,11 +90,13 @@ class Telemetry:
         """Emit ``begin``/``end`` around a code region.  The ``end`` event
         carries ``dur`` (seconds, same clock as ``t``) and ``ok: false``
         when the region raised — a wedged or crashed worker leaves either a
-        dangling ``begin`` or a failed ``end``, both visible in the trace."""
+        dangling ``begin`` or a failed ``end``, both visible in the trace.
+        The region is also a profiler annotation ``repro.<name>@<src>``."""
         t0 = self._clock()
         self._emit("begin", {"span": name, **attrs})
         try:
-            yield
+            with jaxbridge.annotation(name, self.src):
+                yield
         except BaseException:
             self._emit(
                 "end",
@@ -98,10 +112,19 @@ class Telemetry:
         """Emit one complete event of type ``ev`` (plan/cell/totals/...)."""
         self._emit(ev, fields)
 
-    def stage(self, name: str, dur: float, **attrs) -> None:
+    def stage(self, name: str, dur: float, *, end: float | None = None, **attrs) -> None:
         """A completed pipeline-stage interval (the high-frequency form:
-        one line per stage execution, no begin/end pair)."""
-        self._emit("stage", {"stage": name, "dur": round(float(dur), 6), **attrs})
+        one line per stage execution, no begin/end pair), stamped ``t`` at
+        ``end`` on this writer's clock (default: now)."""
+        self._emit("stage", {"stage": name, "dur": round(float(dur), 6), **attrs}, end)
+
+    def stage_scope(self, name: str):
+        """The region of an open pipeline stage; entering it gives the
+        stage's start on this writer's clock.  JAX compiles on this thread
+        inside it are emitted as ``compile.*`` stage events with ``within:
+        name``, and it is a profiler annotation ``repro.<name>@<src>``.  The
+        stage's own event is still the caller's :meth:`stage` call."""
+        return jaxbridge.stage_scope(self, name)
 
     # -- counters & gauges -----------------------------------------------------
     def inc(self, name: str, n=1) -> None:

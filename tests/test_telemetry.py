@@ -397,3 +397,260 @@ def test_cli_summarize_tail_export(traced_run, tmp_path, capsys):
     assert telemetry_cli(["export", traced_run, "-o", dest]) == 0
     with open(dest) as f:
         assert json.load(f)["traceEvents"]
+
+
+# ------------------------------ pallas stages: inputs, JAX compile phases
+
+
+ADD_CONFIGS = [
+    {"t_x": 1, "t_y": 1, "t_z": 1, "w_x": 1, "w_y": 1, "w_z": 1},
+    {"t_x": 2, "t_y": 1, "t_z": 1, "w_x": 1, "w_y": 1, "w_z": 1},
+    {"t_x": 1, "t_y": 2, "t_z": 1, "w_x": 1, "w_y": 1, "w_z": 1},
+]
+COMPILE_PHASES = ("compile.trace", "compile.lower", "compile.backend")
+#: the writer rounds t and dur to the microsecond
+EPS = 2e-6
+
+
+@pytest.fixture
+def cold_jax():
+    """JAX's in-memory caches emptied, so the configs compile again here."""
+    import jax
+
+    jax.clear_caches()
+
+
+def pallas_measurement(**kwargs):
+    from repro.pallas_bench import PallasMeasurement, make_workload
+
+    return PallasMeasurement(make_workload("add", 128, 256), **kwargs)
+
+
+def interval(ev):
+    return float(ev["t"]) - float(ev["dur"]), float(ev["t"])
+
+
+def inside(inner, outer):
+    return outer[0] - EPS <= inner[0] and inner[1] <= outer[1] + EPS
+
+
+def stages_named(events, name, src=None):
+    return [e for e in events if e["ev"] == "stage" and e["stage"] == name
+            and src in (None, e["src"])]
+
+
+def assert_phases_nest(events, src=None):
+    """Every compile phase lies inside a stage of its own writer named by
+    its ``within``, and every compile stage holds a backend compile."""
+    phases = [e for e in events if e["ev"] == "stage" and e["stage"] in COMPILE_PHASES
+              and src in (None, e["src"])]
+    assert {e["stage"] for e in phases} == set(COMPILE_PHASES)
+    for ph in phases:
+        assert ph["within"] in ("compile", "inputs"), ph
+        owners = stages_named(events, ph["within"], ph["src"])
+        assert any(inside(interval(ph), interval(o)) for o in owners), ph
+    for c in stages_named(events, "compile", src):
+        assert any(inside(interval(b), interval(c))
+                   for b in stages_named(events, "compile.backend", c["src"])), c
+
+
+def test_pallas_stages_emit_inputs_and_compile_phases_inside_compile(tmp_path, cold_jax):
+    tel = Telemetry(str(tmp_path / TRACE_FILE))
+    m = pallas_measurement()
+    m.set_telemetry(tel)
+    values = m.measure_batch(ADD_CONFIGS)
+    tel.close()
+    assert np.isfinite(values).all()
+
+    events = read_events(tel.path)
+    compiles = stages_named(events, "compile")
+    assert len(compiles) == m.n_compiles == 3
+    (inputs,) = stages_named(events, "inputs")
+    assert any(inside(interval(inputs), interval(c)) for c in compiles)
+    assert_phases_nest(events)
+    # the sub-stages are the trace's alone: the stage clock keeps four keys
+    assert set(m.provenance()["stage_s"]) == {"screen", "compile", "time", "record"}
+
+
+def test_prefetch_pool_compiles_land_in_the_measuring_writer(tmp_path, cold_jax):
+    tel = Telemetry(str(tmp_path / TRACE_FILE))
+    m = pallas_measurement(pipeline_workers=2)
+    m.set_telemetry(tel)
+    try:
+        m.measure_batch(ADD_CONFIGS)
+    finally:
+        m.close()
+    tel.close()
+    events = read_events(tel.path)
+    assert len(stages_named(events, "compile")) == m.n_compiles == 3
+    assert_phases_nest(events)
+
+
+def test_null_path_registers_no_listener_and_emits_nothing(tmp_path, monkeypatch, cold_jax):
+    import jax
+
+    from repro.telemetry import jaxbridge
+
+    registered = []
+    monkeypatch.setattr(jaxbridge, "_listening", False)
+    monkeypatch.setattr(jax.monitoring, "register_event_duration_secs_listener",
+                        registered.append)
+    m = pallas_measurement()
+    assert m.telemetry is NULL_TELEMETRY
+    m.measure_batch(ADD_CONFIGS[:1])
+    assert registered == [] and not jaxbridge._listening
+    assert NULL_TELEMETRY.stage_scope("compile") is _NULL_SPAN
+    monkeypatch.undo()
+
+    # with the listener live, a compile under the null sink reaches no writer
+    tel = Telemetry(str(tmp_path / TRACE_FILE))
+    traced = pallas_measurement()
+    traced.set_telemetry(tel)
+    traced.measure_batch(ADD_CONFIGS[:1])
+    with open(tel.path) as f:
+        lines = f.read()
+    pallas_measurement().measure_batch(ADD_CONFIGS[1:])
+    with open(tel.path) as f:
+        assert f.read() == lines
+    assert getattr(jaxbridge._bound, "sink", None) is None
+    tel.close()
+
+
+def test_pallas_store_identical_with_telemetry_on_and_off(tmp_path):
+    """On a deterministic timer the same search tells the same values and
+    stores the same entries with a writer attached or not."""
+    from repro.core.engine import DiskCachedMeasurement, drive
+    from repro.core.searchers.base import make_searcher
+    from repro.pallas_bench import default_space
+
+    results = {}
+    for name in ("off", "on"):
+        tel = Telemetry(str(tmp_path / name / TRACE_FILE)) if name == "on" else None
+        inner = pallas_measurement(timer=counter_clock())
+        store = MeasurementStore(str(tmp_path / f"{name}.json"))
+        m = DiskCachedMeasurement(inner, store, prefix="add")
+        m.set_telemetry(tel)
+        searcher = make_searcher("rs", default_space("add", 128, 256), seed=5)
+        result = drive(searcher, m, 4, telemetry=tel)
+        store.save()
+        results[name] = (result.best_config, result.best_value,
+                         sorted(inner.provenance()["stage_s"]))
+        if tel is not None:
+            tel.close()
+            spans = {e.get("span") for e in read_events(tel.path)}
+            assert {"ask", "tell", "round"} <= spans
+    assert results["on"] == results["off"]
+    assert results["on"][2] == ["compile", "record", "screen", "time"]
+    assert store_values_bytes(str(tmp_path / "on.json")) == store_values_bytes(
+        str(tmp_path / "off.json"))
+
+
+SHARDED_COMPILES_SCRIPT = """
+import sys
+import jax
+assert len(jax.devices()) == 4, jax.devices()
+import repro
+from repro.core import ExperimentDesign, TuningSpec
+spec = TuningSpec(
+    kernel="add", backend="pallas",
+    backend_kwargs={"x": 16, "y": 256, "repeats": 1},
+    algorithms=("rs",),
+    design=ExperimentDesign(sample_sizes=(3,), n_experiments=(4,),
+                            final_repeats=1),
+    seed=3,
+)
+repro.tune_matrix(spec, executor="device", max_workers=4, scheduler="static",
+                  telemetry_dir=sys.argv[1])
+"""
+
+
+def test_device_executor_shards_hold_only_their_own_compiles(tmp_path):
+    """Four worker threads, one per (virtual) chip, compile at once: each
+    shard writer's compile phases lie inside that shard's own stages."""
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = {**os.environ, "JAX_PLATFORMS": "cpu",
+           "XLA_FLAGS": "--xla_force_host_platform_device_count=4",
+           "PYTHONPATH": os.path.join(root, "src")}
+    out = subprocess.run(
+        [sys.executable, "-c", SHARDED_COMPILES_SCRIPT, str(tmp_path)],
+        capture_output=True, text=True, env=env, timeout=600,
+    )
+    assert out.returncode == 0, out.stderr[-3000:]
+    events = read_run(str(tmp_path))
+    shards = sorted({e["src"] for e in events if e["src"].startswith("shard")})
+    assert shards == ["shard0", "shard1", "shard2", "shard3"]
+    for src in shards:
+        assert_phases_nest(events, src)
+    # the parent measured nothing and compiled nothing
+    assert not [e for e in events if e["src"] == "main" and e["ev"] == "stage"]
+
+
+def test_profiler_trace_holds_the_program_regions(tmp_path, cold_jax):
+    """A JAX profiler session records the writer's spans and stages as host
+    events ``repro.<name>@<src>``, as the chip benchmark reads them."""
+    import sys
+
+    import jax
+
+    from repro.core.engine import drive
+    from repro.core.searchers.base import make_searcher
+    from repro.pallas_bench import default_space
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from chipbench import devtrace
+
+    tel = Telemetry(str(tmp_path / "run" / TRACE_FILE))
+    m = pallas_measurement()
+    m.set_telemetry(tel)
+    searcher = make_searcher("rs", default_space("add", 128, 256), seed=5)
+    jax.profiler.start_trace(str(tmp_path / "profile"))
+    try:
+        with tel.span("experiment", experiment=0):
+            drive(searcher, m, 2, telemetry=tel)
+    finally:
+        jax.profiler.stop_trace()
+    tel.close()
+
+    names = {name for _, _, name in devtrace.load(str(tmp_path / "profile")).host}
+    regions = {n for n in names if n.startswith("repro.")}
+    assert {"repro.experiment@main", "repro.ask@main", "repro.round@main",
+            "repro.tell@main", "repro.screen@main", "repro.compile@main",
+            "repro.inputs@main", "repro.time@main", "repro.record@main"} <= regions
+    # no region is named like the native host activity the breakdown reads
+    assert not [n for n in regions if devtrace.activity(n) is not None]
+
+
+def test_bridge_writes_traces_outside_lowering_as_their_union(tmp_path):
+    """JAX reports a trace per jitted function: nested traces are written
+    as their union, and traces inside a lowering are left to it."""
+    from repro.telemetry import jaxbridge
+
+    now = [0.0]
+    tel = Telemetry(str(tmp_path / TRACE_FILE), clock=lambda: now[0])
+    with tel.stage_scope("compile"):
+        for end, dur, event in [
+            (1.8, 0.3, "jaxpr_trace_duration"),            # 1.5..1.8, inside the next
+            (2.0, 1.0, "jaxpr_trace_duration"),            # 1.0..2.0
+            (3.1, 0.1, "jaxpr_trace_duration"),            # helpers traced while lowering
+            (3.5, 0.1, "jaxpr_trace_duration"),
+            (4.0, 1.5, "jaxpr_to_mlir_module_duration"),   # 2.5..4.0
+            (5.0, 0.5, "backend_compile_duration"),        # 4.5..5.0
+            (6.0, 0.25, "jaxpr_trace_duration"),           # a trace after the backend
+        ]:
+            now[0] = end
+            jaxbridge._on_duration(f"/jax/core/compile/{event}", dur, fun_name="f")
+        now[0] = 7.0
+    tel.close()
+    got = [(e["stage"], e["t"] - e["dur"], e["t"], e["within"])
+           for e in read_events(tel.path) if e["ev"] == "stage"]
+    assert got == [
+        ("compile.trace", 1.0, 2.0, "compile"),
+        ("compile.lower", 2.5, 4.0, "compile"),
+        ("compile.backend", 4.5, 5.0, "compile"),
+        ("compile.trace", 5.75, 6.0, "compile"),
+    ]
