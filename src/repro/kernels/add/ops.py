@@ -6,9 +6,8 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
-from ..common import Config, KernelBenchSpec, geometry_from_config
+from ..common import Config, KernelBenchSpec, geometry_from_config, standard_normal_images
 from .kernel import add_pallas
 
 
@@ -35,18 +34,10 @@ def add(a: jnp.ndarray, b: jnp.ndarray, config: Config | None = None) -> jnp.nda
     )
 
 
-def _bench_inputs(x: int, y: int, seed: int) -> tuple:
-    rng = np.random.default_rng(seed)
-    return (
-        jnp.asarray(rng.standard_normal((x, y)), jnp.float32),
-        jnp.asarray(rng.standard_normal((x, y)), jnp.float32),
-    )
-
-
 #: resource + input model for the real-measurement backend (pallas_bench)
 BENCH = KernelBenchSpec(
     name="add",
     n_inputs=2,
-    make_inputs=_bench_inputs,
+    make_inputs=lambda x, y, seed: standard_normal_images(x, y, seed, 2),
     run=lambda inputs, cfg, x, y: add(inputs[0], inputs[1], cfg),
 )

@@ -26,10 +26,14 @@ own footprint model (``KernelBenchSpec.vmem_bytes``) fits that same budget.
 from __future__ import annotations
 
 from dataclasses import dataclass, field
+from functools import partial
 from math import ceil
 from typing import Callable
 
 import jax
+import jax.numpy as jnp
+import numpy as np
+from jax import lax
 from jax.experimental.pallas import tpu as pltpu
 
 Config = dict
@@ -69,7 +73,10 @@ class KernelBenchSpec:
     ``vmem_bytes(geometry, y)``.
 
     ``make_inputs(x, y, seed)`` must be a pure function of its arguments so
-    shard workers rebuild bit-identical problems from a JSON spec alone.
+    shard workers rebuild bit-identical problems from a JSON spec alone.  It
+    draws on the device the caller's ``jax.default_device`` names (see
+    :func:`standard_normal_images`), so the identity holds between processes
+    on one backend; across backends the images may differ in their last ulps.
     ``run(inputs, cfg, x, y)`` returns the (possibly still in-flight) device
     array; the harness owns fencing and timing.  ``wz_in_program`` records
     whether ``w_z`` changes the compiled program — today the Pallas/Mosaic
@@ -88,6 +95,43 @@ class KernelBenchSpec:
     vmem_bytes: Callable[[KernelGeometry, int], int] | None = field(
         repr=False, default=None
     )
+
+
+#: pixels, over all the images, one step of the draw's loop fills.  A block
+#: this small keeps the draw's compile short at any image size: on a v5e host
+#: one draw of two whole 8192x8192 images compiles for about 6 s, once per chip
+DRAW_PIXELS = 1 << 16
+
+
+@partial(jax.jit, static_argnames=("x", "y", "n"))
+def _normal_images(seed, *, x: int, y: int, n: int) -> tuple:
+    key = jax.random.key(seed, impl="threefry2x32")
+    rows = max(1, min(x, DRAW_PIXELS // (n * y)))
+
+    def fill(i, images):
+        # the last block is clamped to the images' edge: where rows does not
+        # divide x it draws again rows the block before it filled
+        top = jnp.minimum(i * rows, x - rows)
+        block = jax.random.normal(jax.random.fold_in(key, i), (n, rows, y), jnp.float32)
+        return tuple(
+            lax.dynamic_update_slice(img, block[j], (top, 0)) for j, img in enumerate(images)
+        )
+
+    empty = tuple(jnp.zeros((x, y), jnp.float32) for _ in range(n))
+    return lax.fori_loop(0, -(-x // rows), fill, empty)
+
+
+def standard_normal_images(x: int, y: int, seed: int, n: int) -> tuple:
+    """``n`` f32 ``(x, y)`` images of standard-normal pixels, drawn from
+    ``seed`` by one jitted program on the device the caller's
+    ``jax.default_device`` names: nothing is drawn on the host or copied to
+    the device.  The key names its PRNG (threefry2x32), so a process-wide
+    ``jax_default_prng_impl`` does not change the data.  The images are
+    filled in place, a block of rows at a time (about :data:`DRAW_PIXELS`
+    pixels over all ``n``), each block one draw from the key folded with the
+    block's index.  The same arguments give bit-identical images on one
+    backend; a CPU and a TPU may differ in the last ulps."""
+    return _normal_images(np.uint32(seed), x=x, y=y, n=n)
 
 
 def tiled_vmem_bytes(bench: KernelBenchSpec, g: KernelGeometry) -> int:
@@ -127,8 +171,6 @@ def clamped_index(region: int, local: int, steps: int, n_blocks: int) -> int:
 
     Written with jnp maximum/minimum so it traces inside index_maps.
     """
-    import jax.numpy as jnp
-
     return jnp.minimum(region * steps + local, n_blocks - 1)
 
 

@@ -11,9 +11,14 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
-from ..common import Config, KernelBenchSpec, KernelGeometry, geometry_from_config
+from ..common import (
+    Config,
+    KernelBenchSpec,
+    KernelGeometry,
+    geometry_from_config,
+    standard_normal_images,
+)
 from .kernel import harris_pallas
 
 
@@ -43,11 +48,6 @@ def harris(img: jnp.ndarray, config: Config | None = None) -> jnp.ndarray:
     )
 
 
-def _bench_inputs(x: int, y: int, seed: int) -> tuple:
-    rng = np.random.default_rng(seed)
-    return (jnp.asarray(rng.standard_normal((x, y)), jnp.float32),)
-
-
 #: full-width temporaries per band row that Mosaic keeps in VMEM (padded
 #: band, gradients, their products, box sums, det/trace and the compiler's
 #: copies of them), fitted to the smallest scoped-VMEM limit at which a
@@ -68,7 +68,7 @@ def _vmem_bytes(g: KernelGeometry, y: int) -> int:
 BENCH = KernelBenchSpec(
     name="harris",
     n_inputs=1,
-    make_inputs=_bench_inputs,
+    make_inputs=lambda x, y, seed: standard_normal_images(x, y, seed, 1),
     run=lambda inputs, cfg, x, y: harris(inputs[0], cfg),
     vmem_bytes=_vmem_bytes,
 )

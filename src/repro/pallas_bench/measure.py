@@ -252,8 +252,7 @@ class PallasMeasurement(BaseMeasurement):
         started later — skips this geometry entirely."""
         with self._cache_lock:
             if self._inputs is None:
-                with self._staged("inputs", sub=True):
-                    self._inputs = self.workload.materialize()
+                self._inputs = self._stage_inputs()
             inputs = self._inputs
         run_cfg = self._run_config(cfg)
         pc = self.pcache
@@ -332,11 +331,12 @@ class PallasMeasurement(BaseMeasurement):
         annotates the profiler's trace (``Telemetry.stage_scope``).  The
         event is stamped at the region's start on the writer's clock plus
         the duration, so every event emitted inside the region lies inside
-        the stage's interval."""
+        the stage's interval.  The region is given the event's attributes,
+        a dict the body may add to (a ``None`` value is left out)."""
         with self.telemetry.stage_scope(name) as start:
             t0 = monotonic()
             try:
-                yield
+                yield attrs
             finally:
                 dur = monotonic() - t0
                 if not sub:
@@ -346,6 +346,19 @@ class PallasMeasurement(BaseMeasurement):
                         name, dur, end=start + dur,
                         **{k: v for k, v in attrs.items() if v is not None},
                     )
+
+    def _stage_inputs(self) -> tuple:
+        """The job's input images, drawn on the caller's device and waited
+        for inside the ``inputs`` sub-stage, so the span holds the draw's
+        device time and the draw overlaps no warm-up or timing.  The event
+        records the bytes drawn and the device that holds them."""
+        import jax
+
+        with self._staged("inputs", sub=True) as attrs:
+            inputs = jax.block_until_ready(self.workload.materialize())
+            attrs["bytes"] = sum(a.nbytes for a in inputs)
+            attrs["device"] = str(next(iter(inputs[0].devices()))) if inputs else None
+        return inputs
 
     def _stage_screen(self, config: Config) -> InvalidMeasurement | None:
         """Validity pre-screen; ``None`` means the config may compile."""

@@ -7,9 +7,13 @@ from ``(kernel, x, y, input_seed)``, i.e. from a JSON-serialized
 :class:`~repro.core.api.TuningSpec` alone, so shard workers rebuild
 bit-identical problems without any live objects crossing process boundaries.
 
-Input arrays are drawn from ``np.random.default_rng(stable_seed(...))`` —
-``stable_seed`` is crc32-based and process-invariant (Python's ``hash`` is
-salted), the same discipline the matrix runner uses for experiment seeds.
+Input images are drawn from ``stable_seed(...)`` by a jitted ``jax.random``
+program on the device the caller's ``jax.default_device`` names
+(``kernels.common.standard_normal_images``): nothing is drawn on the host or
+copied over.  ``stable_seed`` is crc32-based and process-invariant (Python's
+``hash`` is salted), the same discipline the matrix runner uses for
+experiment seeds.  The identity holds within a backend: a CPU and a TPU draw
+the same images up to the last ulps.
 """
 
 from __future__ import annotations
@@ -41,8 +45,9 @@ class PallasWorkload:
         return self.bench.name
 
     def materialize(self) -> tuple:
-        """Deterministic input arrays for this problem (pure function of the
-        workload fields — any process gets byte-identical data)."""
+        """Deterministic input arrays for this problem, on the caller's
+        default device (pure function of the workload fields — any process
+        on the same backend gets byte-identical data)."""
         seed = stable_seed("pallas_inputs", self.name, self.x, self.y, self.input_seed)
         return tuple(self.bench.make_inputs(self.x, self.y, seed))
 

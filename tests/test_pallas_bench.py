@@ -87,6 +87,90 @@ def test_workload_inputs_deterministic_across_instances():
     assert not np.array_equal(np.asarray(a1[0]), np.asarray(b[0]))
 
 
+#: kernels with input images, and how many each draws
+INPUT_KERNELS = [("add", 2), ("harris", 1)]
+
+
+@pytest.mark.parametrize("kernel,n", INPUT_KERNELS)
+def test_workload_inputs_are_standard_normal_f32_images(kernel, n):
+    x, y = 256, 384
+    inputs = make_workload(kernel, x, y, input_seed=5).materialize()
+    assert len(inputs) == n
+    # the tolerance chipbench.compare.input_flaws holds a run's images to
+    tol = 6.0 / math.sqrt(x * y)
+    for a in inputs:
+        assert a.shape == (x, y) and a.dtype == np.float32
+        assert abs(float(a.mean())) <= tol
+        assert abs(float(a.std()) - 1.0) <= tol
+    # each image is a draw of its own
+    assert len({np.asarray(a).tobytes() for a in inputs}) == n
+
+
+def test_standard_normal_images_fill_rows_past_the_last_whole_block():
+    from repro.kernels.common import DRAW_PIXELS, standard_normal_images
+
+    x, y = 1001, 256
+    assert x % (DRAW_PIXELS // y)
+    (img,) = standard_normal_images(x, y, 11, 1)
+    img = np.asarray(img)
+    assert img.shape == (x, y)
+    assert (np.abs(img).max(axis=1) > 0).all()
+    tol = 6.0 / math.sqrt(x * y)
+    assert abs(img.mean()) <= tol and abs(img.std() - 1.0) <= tol
+
+
+@pytest.mark.parametrize("kernel,n", INPUT_KERNELS)
+def test_workload_inputs_land_on_the_callers_device(kernel, n):
+    import jax
+
+    device = jax.devices()[-1]
+    with jax.default_device(device):
+        inputs = make_workload(kernel, x=64, y=128).materialize()
+    assert [a.devices() for a in inputs] == [{device}] * n
+
+
+@pytest.mark.parametrize("kernel,n", INPUT_KERNELS)
+def test_workload_inputs_draw_nothing_on_the_host(kernel, n, monkeypatch):
+    def host_draw(*args, **kwargs):
+        raise AssertionError("input images drawn with numpy on the host")
+
+    monkeypatch.setattr(np.random, "default_rng", host_draw)
+    inputs = make_workload(kernel, x=64, y=128, input_seed=3).materialize()
+    assert len(inputs) == n
+
+
+def test_workload_inputs_ignore_the_default_prng_impl():
+    import jax
+
+    want = make_workload("add", x=64, y=128).materialize()
+    before = jax.config.jax_default_prng_impl
+    jax.config.update("jax_default_prng_impl", "rbg")
+    try:
+        got = make_workload("add", x=64, y=128).materialize()
+    finally:
+        jax.config.update("jax_default_prng_impl", before)
+    for u, v in zip(want, got, strict=True):
+        np.testing.assert_array_equal(np.asarray(u), np.asarray(v))
+
+
+@pytest.mark.parametrize("kernel,n", INPUT_KERNELS)
+def test_inputs_stage_event_records_bytes_and_device(kernel, n, tmp_path):
+    import jax
+
+    from repro.telemetry import TRACE_FILE, Telemetry, read_events
+
+    tel = Telemetry(str(tmp_path / TRACE_FILE))
+    m = PallasMeasurement(make_workload(kernel, x=64, y=128), repeats=1)
+    m.set_telemetry(tel)
+    assert np.isfinite(m.measure(GOOD))
+    tel.close()
+    (event,) = [
+        e for e in read_events(tel.path) if e["ev"] == "stage" and e["stage"] == "inputs"
+    ]
+    assert event["bytes"] == n * 64 * 128 * 4
+    assert event["device"] == str(jax.devices()[0])
+
+
 def test_workload_unknown_kernel_and_tiny_problem():
     with pytest.raises(KeyError):
         make_workload("nope")

@@ -21,6 +21,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.add import kernel as add_kernel
 from repro.kernels.add.ops import _add
+from repro.kernels.common import _normal_images
 from repro.kernels.harris import kernel as harris_kernel
 from repro.kernels.harris.ops import _harris
 from repro.kernels.mandelbrot import kernel as mandelbrot_kernel
@@ -107,3 +108,15 @@ def test_screened_in_geometry_compiles_for_v5e(kernel, which, one_chip, mosaic):
     cfg = admitted_geometries(kernel)[which]
     compiled = compile_for_chip(kernel, cfg, one_chip)
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("n", [1, 2])
+def test_input_draw_compiles_for_v5e_in_place(n, one_chip, mosaic):
+    """A job's input images are drawn on the chip: the draw compiles at
+    8192x8192 and writes its images in place, with no stacked copy."""
+    seed = jax.ShapeDtypeStruct((), jnp.uint32, sharding=one_chip)
+    compiled = _normal_images.lower(seed, x=X, y=Y, n=n).compile()
+    mem = compiled.memory_analysis()
+    image = X * Y * 4
+    assert n * image <= mem.output_size_in_bytes < n * image + 4096
+    assert mem.temp_size_in_bytes < image // 64
