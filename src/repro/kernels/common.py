@@ -81,7 +81,11 @@ class KernelBenchSpec:
     array; the harness owns fencing and timing.  ``wz_in_program`` records
     whether ``w_z`` changes the compiled program — today the Pallas/Mosaic
     pipeliner owns buffer counts (see module docstring), so configs differing
-    only in ``w_z`` share one compilation-cache entry.
+    only in ``w_z`` share one compilation-cache entry.  ``program_shape(
+    geometry, x, y)`` optionally describes the program a geometry compiles
+    to, as a dict of numbers the measurement writes onto its ``compile``
+    stage event (harris: the band its body is unrolled over and the rows
+    its entry pads).
     """
 
     name: str
@@ -93,6 +97,9 @@ class KernelBenchSpec:
     bpe: int = 4
     wz_in_program: bool = False
     vmem_bytes: Callable[[KernelGeometry, int], int] | None = field(
+        repr=False, default=None
+    )
+    program_shape: Callable[[KernelGeometry, int, int], dict] | None = field(
         repr=False, default=None
     )
 

@@ -65,10 +65,20 @@ def _vmem_bytes(g: KernelGeometry, y: int) -> int:
     return (blocks + temporaries) * 4
 
 
+def _program_shape(g: KernelGeometry, x: int, y: int) -> dict:
+    """What a geometry compiles to: ``body_pixels``, the halo-extended band
+    (rows_step + 4) x (y + 4) that the shift-and-add body is unrolled over
+    and Mosaic compiles, and ``pad_rows``, the rows :func:`_harris` pads and
+    crops again, one image copy in and one out on every call."""
+    rows = g.rows_step
+    return {"body_pixels": (rows + 4) * (y + 4), "pad_rows": (-x) % rows}
+
+
 BENCH = KernelBenchSpec(
     name="harris",
     n_inputs=1,
     make_inputs=lambda x, y, seed: standard_normal_images(x, y, seed, 1),
     run=lambda inputs, cfg, x, y: harris(inputs[0], cfg),
     vmem_bytes=_vmem_bytes,
+    program_shape=_program_shape,
 )

@@ -374,7 +374,9 @@ class PallasMeasurement(BaseMeasurement):
 
     def _stage_compile(self, config: Config) -> Callable | InvalidMeasurement:
         """Warmed zero-arg runner for cfg's geometry: cache hit, prefetched
-        compile (pipelined batches), or inline compile on first use."""
+        compile (pipelined batches), or inline compile on first use.  A
+        ``compile`` event carries the kernel's ``program_shape`` of cfg
+        beside its ``key``."""
         gkey = self._geom_key(config)
         with self._cache_lock:
             hit = self._compiled.get(gkey)
@@ -387,7 +389,7 @@ class PallasMeasurement(BaseMeasurement):
             # the pool thread charged the compile stage; waiting here is the
             # pipeline's (ideally zero) bubble
             return fut.result()
-        with self._staged("compile", key=str(gkey)):
+        with self._staged("compile", key=str(gkey), **self.workload.program_shape(config)):
             return self._compile_now(config, gkey)
 
     def _stage_time(
@@ -488,7 +490,9 @@ class PallasMeasurement(BaseMeasurement):
     def _prefetch_task(self, cfg: Config, gkey: tuple, device):
         import jax
 
-        with jax.default_device(device), self._staged("compile", key=str(gkey)):
+        with jax.default_device(device), self._staged(
+            "compile", key=str(gkey), **self.workload.program_shape(cfg)
+        ):
             return self._compile_now(cfg, gkey)
 
     def measure_batch(self, configs: Sequence[Config]) -> np.ndarray:

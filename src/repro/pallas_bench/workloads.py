@@ -22,7 +22,7 @@ from dataclasses import dataclass, field
 
 from ..core.runner import stable_seed
 from ..kernels import KERNEL_BENCHES
-from ..kernels.common import Config, KernelBenchSpec, use_interpret
+from ..kernels.common import Config, KernelBenchSpec, geometry_from_config, use_interpret
 
 #: default problem size — small enough that interpret mode (Python-level
 #: grid execution) measures a config in milliseconds, large enough that the
@@ -55,6 +55,12 @@ class PallasWorkload:
         """Launch the kernel; returns the (possibly in-flight) device array.
         The measurement layer owns fencing and timing."""
         return self.bench.run(inputs, cfg, self.x, self.y)
+
+    def program_shape(self, cfg: Config) -> dict:
+        """The kernel's description of the program cfg compiles to at this
+        size (``KernelBenchSpec.program_shape``); empty where it gives none."""
+        shape = self.bench.program_shape
+        return {} if shape is None else shape(geometry_from_config(cfg), self.x, self.y)
 
     def interpret(self) -> bool:
         """Whether ``pl.pallas_call`` runs in interpret mode here (CPU) —

@@ -171,6 +171,46 @@ def test_inputs_stage_event_records_bytes_and_device(kernel, n, tmp_path):
     assert event["device"] == str(jax.devices()[0])
 
 
+def _compile_events(kernel, configs, pipeline_workers, tmp_path):
+    """The ``compile`` stage events of one batch measured at 128x256, and
+    whether the batch's compiles went through the prefetch pool."""
+    from repro.telemetry import TRACE_FILE, Telemetry, read_events
+
+    tel = Telemetry(str(tmp_path / TRACE_FILE))
+    m = PallasMeasurement(
+        make_workload(kernel, x=128, y=256), repeats=1, pipeline_workers=pipeline_workers
+    )
+    m.set_telemetry(tel)
+    assert np.isfinite(m.measure_batch(configs)).all()
+    m.close()
+    tel.close()
+    events = read_events(tel.path)
+    prefetched = any(e["ev"] == "gauge" and e["gauge"] == "prefetch_inflight" for e in events)
+    return [e for e in events if e["ev"] == "stage" and e["stage"] == "compile"], prefetched
+
+
+# 8-row bands divide 128 rows; 24-row bands (t_x=3) pad them to 144
+BANDS = [dict(GOOD, t_x=1, t_z=1), dict(GOOD, t_x=3, t_z=1)]
+
+
+@pytest.mark.parametrize("pipeline_workers", [0, 1], ids=["inline", "prefetched"])
+def test_harris_compile_events_describe_the_compiled_band(pipeline_workers, tmp_path):
+    events, prefetched = _compile_events("harris", BANDS, pipeline_workers, tmp_path)
+    assert prefetched == (pipeline_workers > 0)
+    assert [(e["body_pixels"], e["pad_rows"]) for e in events] == [
+        ((8 + 4) * (256 + 4), 0),
+        ((24 + 4) * (256 + 4), 16),
+    ]
+
+
+@pytest.mark.parametrize("pipeline_workers", [0, 1], ids=["inline", "prefetched"])
+def test_add_compile_events_carry_no_program_shape(pipeline_workers, tmp_path):
+    events, prefetched = _compile_events("add", BANDS, pipeline_workers, tmp_path)
+    assert prefetched == (pipeline_workers > 0)
+    assert len(events) == 2
+    assert not any("body_pixels" in e or "pad_rows" in e for e in events)
+
+
 def test_workload_unknown_kernel_and_tiny_problem():
     with pytest.raises(KeyError):
         make_workload("nope")
