@@ -19,7 +19,7 @@ from ..common import (
     geometry_from_config,
     standard_normal_images,
 )
-from .kernel import harris_pallas
+from .kernel import harris_pallas, window_pixels
 
 
 @partial(jax.jit, static_argnames=("t_x", "t_y", "t_z", "w_x", "w_y", "w_z"))
@@ -48,17 +48,23 @@ def harris(img: jnp.ndarray, config: Config | None = None) -> jnp.ndarray:
     )
 
 
-#: full-width temporaries per band row that Mosaic keeps in VMEM (padded
-#: band, gradients, their products, box sums, det/trace and the compiler's
-#: copies of them), fitted to the smallest scoped-VMEM limit at which a
-#: described v5e compiles bands of 8 to 128 rows at widths 256 to 8192
+#: full-width temporaries per band row that Mosaic kept in VMEM when the
+#: kernel's body ran over the whole band (padded band, gradients, their
+#: products, box sums, det/trace and the compiler's copies of them), fitted to
+#: the smallest scoped-VMEM limit at which a described v5e compiled bands of 8
+#: to 128 rows at widths 256 to 8192.  The tiled kernel holds less: one
+#: (rows_step + 16) x (y + 256) scratch, and one window's temporaries.  The
+#: model now over-reserves, and is kept as it is because the screen it bounds
+#: decides which configs a searcher may draw: a smaller model would admit taller
+#: bands and change the configs every fixed-seed tuning job measures
 BAND_TEMPORARIES = 23
 
 
 def _vmem_bytes(g: KernelGeometry, y: int) -> int:
     """Mosaic's VMEM for one band: two pipeline buffers each of the two
-    8-row halo slabs, the band and the output band, all full-width, plus the
-    full-width temporaries over the lane-padded, halo-extended width."""
+    8-row halo slabs, the band and the output band, all full-width, plus
+    the reserve of :data:`BAND_TEMPORARIES` band rows over the lane-padded,
+    halo-extended width."""
     rows = g.rows_step
     blocks = 2 * (2 * 8 + 2 * rows) * y
     temporaries = BAND_TEMPORARIES * rows * (-(-(y + 4) // 128) * 128)
@@ -66,12 +72,12 @@ def _vmem_bytes(g: KernelGeometry, y: int) -> int:
 
 
 def _program_shape(g: KernelGeometry, x: int, y: int) -> dict:
-    """What a geometry compiles to: ``body_pixels``, the halo-extended band
-    (rows_step + 4) x (y + 4) that the shift-and-add body is unrolled over
-    and Mosaic compiles, and ``pad_rows``, the rows :func:`_harris` pads and
-    crops again, one image copy in and one out on every call."""
-    rows = g.rows_step
-    return {"body_pixels": (rows + 4) * (y + 4), "pad_rows": (-x) % rows}
+    """What a geometry compiles to: ``body_pixels``, the halo-extended window
+    of one output tile that the kernel's shift-and-add body is unrolled over
+    and Mosaic compiles (the same for every band on an image ``y`` wide), and
+    ``pad_rows``, the rows :func:`_harris` pads and crops again, one image
+    copy in and one out on every call."""
+    return {"body_pixels": window_pixels(y), "pad_rows": (-x) % g.rows_step}
 
 
 BENCH = KernelBenchSpec(

@@ -24,6 +24,13 @@ CONFIGS = [
 
 SHAPES = [(64, 128), (128, 256), (96, 384), (40, 128)]
 
+# harris walks each band in tiles of 8 rows x 1024 lanes: besides the cases
+# above, a width the tile does not divide (1152), widths narrower than a tile
+# (640, and 600, no multiple of the 128-lane vreg either) and bands of 64 rows,
+# one of them split in two row regions
+HARRIS_SHAPES = SHAPES + [(72, 640), (136, 1152), (24, 600)]
+HARRIS_CONFIGS = CONFIGS + [dict(t_x=8), dict(t_x=2, t_z=4, w_x=2)]
+
 
 @pytest.mark.parametrize("shape", SHAPES)
 @pytest.mark.parametrize("cfg", CONFIGS)
@@ -37,8 +44,8 @@ def test_add_matches_ref(shape, cfg, dtype):
     assert reference_mismatch("add", out, add_ref(a, b)) is None
 
 
-@pytest.mark.parametrize("shape", SHAPES)
-@pytest.mark.parametrize("cfg", CONFIGS)
+@pytest.mark.parametrize("shape", HARRIS_SHAPES)
+@pytest.mark.parametrize("cfg", HARRIS_CONFIGS)
 def test_harris_matches_ref(shape, cfg):
     rng = np.random.default_rng(1)
     img = jnp.asarray(rng.normal(size=shape), jnp.float32)

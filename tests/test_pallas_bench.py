@@ -8,6 +8,7 @@ name-serializable ``BACKENDS["pallas"]`` path through ``repro.tune`` /
 sharded ``tune_matrix``, and zero-recompile warm-store re-runs.
 """
 
+import itertools
 import json
 import math
 import shutil
@@ -197,10 +198,23 @@ BANDS = [dict(GOOD, t_x=1, t_z=1), dict(GOOD, t_x=3, t_z=1)]
 def test_harris_compile_events_describe_the_compiled_band(pipeline_workers, tmp_path):
     events, prefetched = _compile_events("harris", BANDS, pipeline_workers, tmp_path)
     assert prefetched == (pipeline_workers > 0)
+    # one 8-row tile, its 2 halo rows each side, 256 lanes and the 128-lane
+    # margins each side: the same whatever the band
     assert [(e["body_pixels"], e["pad_rows"]) for e in events] == [
-        ((8 + 4) * (256 + 4), 0),
-        ((24 + 4) * (256 + 4), 16),
+        ((8 + 4) * (256 + 256), 0),
+        ((8 + 4) * (256 + 256), 16),
     ]
+    # the screen admits what it admitted when the kernel's body ran over the
+    # whole band, so a fixed-seed tuning job at 8192x8192 draws the same configs
+    w = make_workload("harris", x=8192, y=8192)
+    space = default_space("harris", x=8192, y=8192)
+    values = {p.name: p.values for p in space.params}
+    axes = [values[n] for n in ("t_x", "t_y", "t_z", "w_x", "w_y")]
+    admitted = sum(
+        validate_config(w, dict(t_x=tx, t_y=ty, t_z=tz, w_x=wx, w_y=wy, w_z=1)) is None
+        for tx, ty, tz, wx, wy in itertools.product(*axes)
+    )
+    assert admitted == 23504
 
 
 @pytest.mark.parametrize("pipeline_workers", [0, 1], ids=["inline", "prefetched"])

@@ -110,6 +110,24 @@ def test_screened_in_geometry_compiles_for_v5e(kernel, which, one_chip, mosaic):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def test_harris_compiled_code_does_not_grow_with_the_band(one_chip, mosaic):
+    """Mosaic compiles one fixed-size tile body for every harris band: the
+    code generated for the tallest band the screen admits at 8192x8192 (72
+    rows, with its row pad and crop) is within 1.5x of the smallest's (8
+    rows).  A body unrolled over the whole band grew with it, 7.2x between
+    these two.  The serialized Mosaic module cannot show this: it holds the
+    kernel before Mosaic lays its vectors out, and its size does not depend
+    on the band."""
+    geometries = admitted_geometries("harris")
+    smallest, tallest = (
+        compile_for_chip("harris", geometries[which], one_chip)
+        .memory_analysis()
+        .generated_code_size_in_bytes
+        for which in ("smallest", "tallest")
+    )
+    assert tallest <= 1.5 * smallest
+
+
 @pytest.mark.parametrize("n", [1, 2])
 def test_input_draw_compiles_for_v5e_in_place(n, one_chip, mosaic):
     """A job's input images are drawn on the chip: the draw compiles at
